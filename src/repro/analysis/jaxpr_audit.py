@@ -98,7 +98,7 @@ def _with_backend(cfg, backend: str):
 
 def _sub_jaxprs(eqn):
     """(param_name, jaxpr) pairs for every sub-jaxpr of an equation."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     out = []
     for k, v in eqn.params.items():

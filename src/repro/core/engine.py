@@ -31,6 +31,7 @@ from typing import Callable, Dict, List, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, PartitionSpec
 
 from repro.core import omfs_jax, policies_jax
 from repro.core.baselines import ALL_BASELINES
@@ -576,6 +577,12 @@ class BatchCell:
     pass_depth: Optional[int] = None
 
 
+def _batch_mesh(n_dev: int) -> Mesh:
+    """1-D mesh over the first ``n_dev`` local devices; the batch axis of
+    `simulate_batch` is split along its ``"b"`` axis."""
+    return Mesh(np.asarray(jax.devices()[:n_dev]), ("b",))
+
+
 @functools.lru_cache(maxsize=16)
 def _jitted_batch_runner(cfg: SchedulerConfig, pass_fns: tuple, horizon: int,
                          n_dev: int = 1):
@@ -599,14 +606,10 @@ def _jitted_batch_runner(cfg: SchedulerConfig, pass_fns: tuple, horizon: int,
 
     vcell = jax.vmap(cell)
     if n_dev > 1:
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, PartitionSpec
-
-        mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("b",))
         spec = PartitionSpec("b")
-        vcell = shard_map(vcell, mesh=mesh,
-                          in_specs=(spec, spec, spec, spec),
-                          out_specs=(spec, spec), check_rep=False)
+        vcell = jax.shard_map(vcell, mesh=_batch_mesh(n_dev),
+                              in_specs=(spec, spec, spec, spec),
+                              out_specs=(spec, spec), check_vma=False)
     return jax.jit(vcell, donate_argnums=(0,))
 
 
@@ -635,15 +638,11 @@ def _jitted_batch_runner_events(cfg: SchedulerConfig, pass_fns: tuple,
 
     vcell = jax.vmap(cell)
     if n_dev > 1:
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, PartitionSpec
-
-        mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("b",))
         spec = PartitionSpec("b")
-        vcell = shard_map(vcell, mesh=mesh,
-                          in_specs=(spec, spec, spec, spec),
-                          out_specs=(spec, (spec, spec, spec, spec)),
-                          check_rep=False)
+        vcell = jax.shard_map(vcell, mesh=_batch_mesh(n_dev),
+                              in_specs=(spec, spec, spec, spec),
+                              out_specs=(spec, (spec, spec, spec, spec)),
+                              check_vma=False)
     return jax.jit(vcell, donate_argnums=(0,))
 
 

@@ -408,12 +408,14 @@ def plan_evictions(cfg: SchedulerConfig, tbl: JobTable, evictable: jax.Array,
     * ``"pallas"`` / ``"pallas_interpret"`` — the fused
       `kernels.sched_select` kernel: masked bitonic sort + prefix-sum
       cutoff + greedy T-tier placement over the effective save lattice in
-      one ``pallas_call`` (interpret mode off-TPU, or always for
-      ``"pallas_interpret"``).  Placement here is computed on the
-      pre-feasibility-mask ``planned``; callers mask ``planned`` with an
-      all-or-nothing scalar, and every table write in `apply_evictions` is
-      gated on the masked victim set, so the results are bit-identical
-      either way.
+      one ``pallas_call``.  ``"pallas"`` compiles it for the TPU and fails
+      to lower anywhere else; ``"pallas_interpret"`` runs it in the Pallas
+      interpreter on any backend.  Tables above
+      `kernels.sched_select.ops.MAX_JOBS` rows raise.  Placement here is
+      computed on the pre-feasibility-mask ``planned``; callers mask
+      ``planned`` with an all-or-nothing scalar, and every table write in
+      `apply_evictions` is gated on the masked victim set, so the results
+      are bit-identical either way.
 
     The dispatch is a static Python branch on the (hashable, jit-static)
     config, so each backend traces its own program — toggling the flag
@@ -430,8 +432,7 @@ def plan_evictions(cfg: SchedulerConfig, tbl: JobTable, evictable: jax.Array,
                          f"{backend!r}: expected 'lax', 'pallas' or "
                          f"'pallas_interpret'")
     from repro.kernels.sched_select.ops import plan_evictions_fused
-    interpret = (backend == "pallas_interpret"
-                 or jax.default_backend() != "tpu")
+    interpret = backend == "pallas_interpret"
     tiered = _tiered(cfg)
     eff_lat = effective_save_lat(tbl)
     if tiered:

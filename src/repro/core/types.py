@@ -109,8 +109,10 @@ class SchedulerConfig:
     # Which implementation serves the eviction machinery (victim sort,
     # capacity cutoff, tier placement) inside every C/R-aware pass:
     #   "lax"              — jnp.lexsort + lax.scan (default; best on CPU)
-    #   "pallas"           — fused `kernels.sched_select`; interprets off-TPU
-    #   "pallas_interpret" — same kernel, interpret forced (CI / tests)
+    #   "pallas"           — fused `kernels.sched_select`, compiled for the
+    #                        TPU (raises on any other backend, and above
+    #                        `kernels.sched_select.ops.MAX_JOBS` rows)
+    #   "pallas_interpret" — same kernel in the Pallas interpreter (CPU tests)
     # The flag rides every lru-cached runner key (the config is the key), so
     # toggling it selects a separately cached runner — never a retrace.
     kernel_backend: str = "lax"

@@ -46,21 +46,11 @@ def embed_lookup(table: jax.Array, tokens: jax.Array, mesh) -> jax.Array:
 
 
 def usable_mesh(min_model: int = 2):
-    """The ambient abstract mesh if it has a >1 'model' axis, else None.
-
-    `jax.sharding.get_abstract_mesh` is only public from jax 0.5; on older
-    runtimes we fall back to the private accessor, and on versions whose
-    AbstractMesh lacks `.empty`/`.axis_names` (e.g. 0.4.x returns a bare
-    tuple-like) we treat the ambient mesh as absent — computations then run
-    unsharded, which is correct on a single-device pool."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        try:
-            from jax._src.mesh import get_abstract_mesh as get
-        except ImportError:
-            return None
-    mesh = get()
-    if mesh is None or getattr(mesh, "empty", True):
+    """The ambient abstract mesh if it has a >1 'model' axis, else None
+    (computations then run unsharded, which is correct on a single-device
+    pool)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return None
     if "model" not in mesh.axis_names or mesh.shape["model"] < min_model:
         return None

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -150,7 +149,7 @@ def flash_attention_fwd(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

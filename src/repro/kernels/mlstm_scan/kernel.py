@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
 
 NEG_BIG = -1e30
 
@@ -169,7 +168,7 @@ def mlstm_scan(
             pltpu.VMEM((dh,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

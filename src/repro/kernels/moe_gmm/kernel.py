@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k_blocks: int):
     kk = pl.program_id(3)
@@ -63,7 +61,7 @@ def grouped_matmul(
         out_specs=pl.BlockSpec((None, bc, bf), lambda e_, i, j, kk: (e_, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

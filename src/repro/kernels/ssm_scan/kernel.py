@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _ssm_kernel(
     delta_ref,    # [chunk, bd]
@@ -107,7 +105,7 @@ def ssm_scan(
             jax.ShapeDtypeStruct((bsz, di, ds), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bd, ds), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

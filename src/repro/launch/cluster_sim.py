@@ -11,9 +11,11 @@ from repro.core.crcost import UNBOUNDED, CRCostModel, TieredCRCostModel
 from repro.core.metrics import compute_metrics
 from repro.core.types import SchedulerConfig
 from repro.core.workload import WorkloadSpec, make_jobs, make_users
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--policy", default="omfs", choices=sorted(engine.POLICIES))
     ap.add_argument("--backend", default="python", choices=["python", "jax"])

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.models.model import build_model
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def sched_status_payloads(args):
@@ -94,6 +95,7 @@ def serve_sched_status(args):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true")

@@ -24,9 +24,11 @@ from repro.models.model import build_model
 from repro.optim.compression import compress_tree, init_ef
 from repro.train.state import init_train_state, train_state_shapes
 from repro.train.steps import TrainConfig, make_train_step
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--smoke", action="store_true",

@@ -6,8 +6,8 @@ without this, every property-test module dies at import time.  The stub
 (`tests/_hypothesis_stub.py`) draws a fixed seeded example set per test;
 with the real package installed this file is a no-op.
 
-The collection hook applies ``tests/known_failures.toml`` (the triaged
-kernel/multidevice gaps) as **strict** xfails: a listed test that starts
+The collection hook applies ``tests/known_failures.toml`` (triaged
+expected failures; empty when none) as **strict** xfails: a listed test that starts
 passing fails the run — stale entries cannot linger — and an unlisted test
 that breaks fails normally.  The registry format itself is validated by
 ``python -m repro.analysis`` (rule: known-failures).

@@ -261,12 +261,42 @@ def test_event_schema_flags_missing_schema_files(tmp_path):
     assert any("no in-scan emitter" in m for m in msgs)
 
 
-def test_known_failures_registry_valid_and_loadable():
+def test_known_failures_registry_valid_and_loadable(tmp_path):
+    """The repo's registry passes the rule and loads; a registry written to
+    ``tmp_path`` loads when well-formed (empty included) and is flagged
+    entry by entry when malformed."""
     assert known_failures.check_known_failures(REPO) == []
-    known = known_failures.load_known_failures(REPO)
-    assert len(known) >= 1
-    for nodeid, reason in known.items():
+    for nodeid, reason in known_failures.load_known_failures(REPO).items():
         assert "::" in nodeid and reason.strip()
+
+    reg = tmp_path / known_failures.REGISTRY
+    reg.parent.mkdir(parents=True)
+    (tmp_path / "tests" / "test_x.py").write_text("")
+    reg.write_text("# nothing expected to fail\n")
+    assert known_failures.check_known_failures(tmp_path) == []
+    assert known_failures.load_known_failures(tmp_path) == {}
+
+    reg.write_text('[[failure]]\nid = "tests/test_x.py::test_a"\n'
+                   'reason = "waits on a fix"\n')
+    assert known_failures.check_known_failures(tmp_path) == []
+    assert known_failures.load_known_failures(tmp_path) == {
+        "tests/test_x.py::test_a": "waits on a fix"}
+
+    reg.write_text('[[failure]]\nid = "tests/test_x.py::test_a"\n'
+                   'reason = "x"\n'
+                   '[[failure]]\nid = "tests/test_x.py::test_a"\n'
+                   'reason = ""\n'
+                   '[[failure]]\nid = "tests/test_gone.py::test_b"\n'
+                   'reason = "x"\nowner = "y"\n'
+                   '[[failure]]\nid = "not_a_nodeid"\nreason = "x"\n')
+    msgs = [v.message for v in known_failures.check_known_failures(tmp_path)]
+    for part in ("duplicate id", "has no reason", "missing file",
+                 "unknown key", "pytest nodeid"):
+        assert any(part in m for m in msgs), (part, msgs)
+
+    reg.write_text("[[failure]\n")
+    msgs = [v.message for v in known_failures.check_known_failures(tmp_path)]
+    assert len(msgs) == 1 and "does not parse" in msgs[0]
 
 
 def test_github_summary_format():

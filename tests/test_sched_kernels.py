@@ -257,16 +257,28 @@ def test_reference_pass_pallas():
 # ---------------------------------------------------------------------------
 
 
-def test_pallas_auto_interprets_off_tpu():
-    """``kernel_backend="pallas"`` falls back to interpret mode away from
-    TPUs instead of failing to lower — same results."""
+def test_pallas_raises_off_tpu():
+    """``kernel_backend="pallas"`` compiles the kernel for the TPU or
+    raises: away from a TPU it never falls back to interpret mode."""
+    import jax
+    assert jax.default_backend() != "tpu"
     users, jobs = _workload(seed=1)
-    cfg = SchedulerConfig(cpu_total=32, quantum=2)
-    lax = engine.simulate(users, jobs, cfg, 60, policy="omfs", backend="jax")
-    pal = engine.simulate(users, jobs,
-                          dataclasses.replace(cfg, kernel_backend="pallas"),
-                          60, policy="omfs", backend="jax")
-    _assert_results_equal(lax, pal)
+    cfg = SchedulerConfig(cpu_total=32, quantum=2, kernel_backend="pallas")
+    with pytest.raises(ValueError, match="interpret"):
+        engine.simulate(users, jobs, cfg, 60, policy="omfs", backend="jax")
+
+
+def test_kernel_rejects_tables_above_vmem_limit():
+    """Above `ops.MAX_JOBS` rows the kernel raises, naming the limit,
+    instead of falling back to the lax path."""
+    from repro.kernels.sched_select.ops import MAX_JOBS
+    j = MAX_JOBS + 1
+    z = np.zeros(j, np.int32)
+    with pytest.raises(ValueError, match=str(MAX_JOBS)):
+        plan_evictions_fused(z, z, z, z, z.astype(bool), z, z,
+                             z.astype(bool), z.reshape(j, 1), 0, 0,
+                             np.zeros(1, np.int32), np.full(1, -1, np.int32),
+                             interpret=True)
 
 
 def test_unknown_backend_raises():
