@@ -1,0 +1,9 @@
+"""sort.busy_share: device time in HLO sort operations (the queue and
+victim lexsorts) as a share of device busy time, from the trace."""
+
+
+def read(ctx):
+    tr = ctx.get("trace")
+    if not tr or tr.get("sort_s") is None:
+        return None
+    return 100.0 * tr["sort_s"] / tr["busy_s"]
