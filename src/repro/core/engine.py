@@ -23,7 +23,6 @@ what the per-policy Python-vs-JAX property tests assert.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple, Union
@@ -37,9 +36,6 @@ from repro.core import omfs_jax, policies_jax
 from repro.core.baselines import ALL_BASELINES
 from repro.core.omfs import Decision, cheap_victim_pass, scheduler_pass
 from repro.core.types import ClusterState, Job, JobState, SchedulerConfig, User
-
-#: reusable no-op context (profiling-off paths in `simulate_stream`)
-_NULLCTX = contextlib.nullcontext()
 
 PythonPolicy = Callable[[ClusterState], List[Decision]]
 # JAX policy contract: pass_fn(cfg, entitled[U], t, JobTable) -> JobTable
@@ -857,11 +853,18 @@ def simulate_stream(
     ``record_events`` captures the lifecycle event log in-scan exactly like
     `simulate` (the ring records true job ids, so recycled slots decode
     correctly and finished jobs' events survive compaction — they were
-    captured at their tick, before the row was archived).  ``profile`` is an
-    optional `repro.obs.profile.ProfileTimers`; when given, the stream is
-    timed into three sections — ``compile`` (segment-runner builds),
-    ``dispatch`` (jitted segment execution), ``compaction`` (the host-side
-    boundary) — surfaced by the scale bench.
+    captured at their tick, before the row was archived).
+
+    Every round opens the ``stream.*`` spans of `repro.obs.profile.span`
+    (profiler annotations, free when no profiler runs), with the round's
+    counters as arguments: ``finished``, ``inserted``, ``deferred`` and
+    ``live`` on ``stream.boundary``; ``t0``, ``ticks`` and ``fresh`` on
+    ``stream.segment``.  ``profile`` is an optional section hook (an object
+    with ``section(name)``, such as `repro.obs.profile.ProfileTimers`); it
+    receives exactly three sections: ``compaction`` (the host boundary),
+    then ``compile`` (the segment runner was built in this call) or
+    ``dispatch`` (it was not), each segment ending in
+    ``block_until_ready``.
     """
     if capacity <= 0:
         raise ValueError(f"capacity must be positive, got {capacity}")
@@ -870,6 +873,7 @@ def simulate_stream(
     if not isinstance(policy, str) or policy not in POLICIES:
         raise ValueError(
             f"unknown policy {policy!r}; known: {sorted(POLICIES)}")
+    from repro.obs.profile import span
     pass_fn = POLICIES[policy].jax_factory(pass_depth)
 
     ring: Optional[int] = None
@@ -890,113 +894,137 @@ def simulate_stream(
     stats = {"segments": 0, "inserted": 0, "deferrals": 0, "peak_live": 0,
              "capacity": capacity}
 
-    def boundary(tbl):
-        """Compact finished rows out, insert due arrivals; host-side."""
-        host = jax.device_get(tbl)
-        pad = np.asarray(omfs_jax.is_pad(host))
-        finished = np.isin(np.asarray(host.state),
-                           (int(omfs_jax.DONE), int(omfs_jax.KILLED))) & ~pad
-        if finished.any():
-            idx = np.flatnonzero(finished)
-            archived.append(jax.tree_util.tree_map(lambda a: a[idx], host))
-        free = np.flatnonzero(finished | pad)
-        stats["peak_live"] = max(stats["peak_live"], capacity - free.size)
-        k = min(len(due), free.size)
-        if k < len(due):
-            stats["deferrals"] += len(due) - k
-        if k == 0 and not finished.any():
+    def boundary(tbl, note):
+        """Compact finished rows out, insert due arrivals; host-side.  The
+        round's counters go on ``note``, the boundary's annotation."""
+        with span("stream.read_back"):
+            host = jax.device_get(tbl)
+        with span("stream.compact"):
+            pad = np.asarray(omfs_jax.is_pad(host))
+            finished = np.isin(np.asarray(host.state),
+                               (int(omfs_jax.DONE), int(omfs_jax.KILLED))
+                               ) & ~pad
+            n_finished = 0
+            if finished.any():
+                idx = np.flatnonzero(finished)
+                n_finished = idx.size
+                archived.append(
+                    jax.tree_util.tree_map(lambda a: a[idx], host))
+            free = np.flatnonzero(finished | pad)
+            stats["peak_live"] = max(stats["peak_live"],
+                                     capacity - free.size)
+            k = min(len(due), free.size)
+            if k < len(due):
+                stats["deferrals"] += len(due) - k
+        note.set_metadata(finished=n_finished, inserted=k,
+                          deferred=len(due) - k,
+                          live=capacity - free.size + k)
+        if k == 0 and not n_finished:
             return tbl
         take, due[:] = due[:k], due[k:]
-        block, _ = omfs_jax.table_from_jobs(take, users, config.cpu_total,
-                                            config)
-        rows = omfs_jax.pad_table(block, capacity)
-        # arrivals fill the first k free slots; pad rows clear the rest of
-        # the freed slots; occupied slots get a masked write-back.  `slots`
-        # is a permutation of arange(capacity) by construction.
-        slots = np.concatenate(
-            [free, np.setdiff1d(np.arange(capacity), free)])
-        valid = np.arange(capacity) < free.size
-        stats["inserted"] += k
-        return omfs_jax.insert_rows(tbl, jnp.asarray(slots, jnp.int32),
-                                    rows, jnp.asarray(valid))
+        with span("stream.build"):
+            block, _ = omfs_jax.table_from_jobs(take, users,
+                                                config.cpu_total, config)
+            rows = omfs_jax.pad_table(block, capacity)
+        with span("stream.insert"):
+            # arrivals fill the first k free slots; pad rows clear the rest
+            # of the freed slots; occupied slots get a masked write-back.
+            # `slots` is a permutation of arange(capacity) by construction.
+            slots = np.concatenate(
+                [free, np.setdiff1d(np.arange(capacity), free)])
+            valid = np.arange(capacity) < free.size
+            stats["inserted"] += k
+            return omfs_jax.insert_rows(tbl, jnp.asarray(slots, jnp.int32),
+                                        rows, jnp.asarray(valid))
 
     ev_counts: List[np.ndarray] = []
     ev_rings: List[np.ndarray] = []
     ev_dropped: List[np.ndarray] = []
     seg_starts: List[int] = []
 
+    # the host blocks on each segment at block_until_ready when a profile
+    # times it or events are read, else at the busy read-back
+    blocking = record_events or profile is not None
     t0 = 0
     while t0 < horizon:
         seg = min(segment_len, horizon - t0)
-        while True:
-            if lookahead is None:
-                lookahead = next(feed, None)
-            if lookahead is None or lookahead.submit_time >= t0 + seg:
-                break
-            due.append(lookahead)
-            lookahead = None
-        if profile is not None:
-            with profile.section("compaction"):
-                tbl = boundary(tbl)
-        else:
-            tbl = boundary(tbl)
-        if record_events:
-            builder, key = _jitted_segment_runner_events, (
-                config, pass_fn, seg, ring)
-        else:
-            builder, key = _jitted_segment_runner, (config, pass_fn, seg)
-        # a builder cache miss means this call traces + XLA-compiles the
-        # segment program; later segments of the stream only dispatch it
-        misses = builder.cache_info().misses
-        runner = builder(*key)
-        fresh = builder.cache_info().misses > misses
-        with (profile.section("compile" if fresh else "dispatch")
-              if profile is not None else _NULLCTX):
+        with span("stream.round"):
+            with span("stream.feed"):
+                while True:
+                    if lookahead is None:
+                        lookahead = next(feed, None)
+                    if (lookahead is None
+                            or lookahead.submit_time >= t0 + seg):
+                        break
+                    due.append(lookahead)
+                    lookahead = None
+            with span("stream.boundary", profile, "compaction") as note:
+                tbl = boundary(tbl, note)
             if record_events:
-                tbl, (busy, cnt, rbuf, drp) = runner(tbl, ent, jnp.int32(t0))
-                busy = jax.block_until_ready(busy)
-                ev_counts.append(np.asarray(cnt))
-                ev_rings.append(np.asarray(rbuf))
-                ev_dropped.append(np.asarray(drp))
-                seg_starts.append(t0)
+                factory, key = _jitted_segment_runner_events, (
+                    config, pass_fn, seg, ring)
             else:
-                tbl, busy = runner(tbl, ent, jnp.int32(t0))
-                if profile is not None:
-                    busy = jax.block_until_ready(busy)
-        busy_parts.append(np.asarray(busy))
-        stats["segments"] += 1
+                factory, key = _jitted_segment_runner, (config, pass_fn, seg)
+            # a cache miss of the runner factory means this call traces +
+            # XLA-compiles the segment program; later segments dispatch it
+            misses = factory.cache_info().misses
+            runner = factory(*key)
+            fresh = factory.cache_info().misses > misses
+            with span("stream.segment", profile,
+                      "compile" if fresh else "dispatch",
+                      t0=t0, ticks=seg, fresh=int(fresh)):
+                with span("stream.dispatch"):
+                    tbl, out = runner(tbl, ent, jnp.int32(t0))
+                with span("stream.wait"):
+                    if record_events:
+                        busy, cnt, rbuf, drp = out
+                        busy = jax.block_until_ready(busy)
+                        ev_counts.append(np.asarray(cnt))
+                        ev_rings.append(np.asarray(rbuf))
+                        ev_dropped.append(np.asarray(drp))
+                        seg_starts.append(t0)
+                    elif blocking:
+                        busy = jax.block_until_ready(out)
+                    else:
+                        busy = np.asarray(out)
+            busy_parts.append(np.asarray(busy))
+            stats["segments"] += 1
         t0 += seg
 
     # final extraction: archive + still-live rows, merged in job-id order
     # (= the monolithic table's row order).  Arrivals still deferred here
     # never entered the table; they stay out of the result (counted below).
-    stats["dropped"] = len(due)
-    host = jax.device_get(tbl)
-    live = np.flatnonzero(~np.asarray(omfs_jax.is_pad(host)))
-    parts = archived + [jax.tree_util.tree_map(lambda a: a[live], host)]
-    merged_np = {
-        f: np.concatenate([np.asarray(getattr(p, f)) for p in parts])
-        for f in omfs_jax.JobTable._fields}
-    order = np.argsort(merged_np["jid"], kind="stable")
-    merged = omfs_jax.JobTable(**{
-        f: jnp.asarray(v[order], jnp.int32) for f, v in merged_np.items()})
-    busy = (np.concatenate(busy_parts) if busy_parts
-            else np.zeros((0,), np.int32))
-    res = EngineResult(policy=policy, backend="jax", config=config,
-                       table=merged, busy=busy, stream_stats=stats)
-    if record_events:
-        from repro.obs import jax_capture
-        from repro.obs.events import N_EVENT_TYPES
-        events = []
-        for cnt, rbuf, drp, s0 in zip(ev_counts, ev_rings, ev_dropped,
-                                      seg_starts):
-            events.extend(jax_capture.decode_events(cnt, rbuf, drp, t0=s0))
-        res.events = events
-        res.event_counts = (
-            np.concatenate(ev_counts).astype(np.int64) if ev_counts
-            else np.zeros((0, N_EVENT_TYPES), np.int64))
-        res.events_dropped = (
-            np.concatenate(ev_dropped).astype(np.int64) if ev_dropped
-            else np.zeros((0,), np.int64))
-        stats["events_dropped"] = int(res.events_dropped.sum())
+    with span("stream.extract"):
+        stats["dropped"] = len(due)
+        host = jax.device_get(tbl)
+        live = np.flatnonzero(~np.asarray(omfs_jax.is_pad(host)))
+        parts = archived + [
+            jax.tree_util.tree_map(lambda a: a[live], host)]
+        merged_np = {
+            f: np.concatenate([np.asarray(getattr(p, f)) for p in parts])
+            for f in omfs_jax.JobTable._fields}
+        order = np.argsort(merged_np["jid"], kind="stable")
+        merged = omfs_jax.JobTable(**{
+            f: jnp.asarray(v[order], jnp.int32)
+            for f, v in merged_np.items()})
+        busy = (np.concatenate(busy_parts) if busy_parts
+                else np.zeros((0,), np.int32))
+        res = EngineResult(policy=policy, backend="jax", config=config,
+                           table=merged, busy=busy, stream_stats=stats)
+        if record_events:
+            from repro.obs import jax_capture
+            from repro.obs.events import N_EVENT_TYPES
+            events = []
+            for cnt, rbuf, drp, s0 in zip(ev_counts, ev_rings, ev_dropped,
+                                          seg_starts):
+                events.extend(
+                    jax_capture.decode_events(cnt, rbuf, drp, t0=s0))
+            res.events = events
+            res.event_counts = (
+                np.concatenate(ev_counts).astype(np.int64) if ev_counts
+                else np.zeros((0, N_EVENT_TYPES), np.int64))
+            res.events_dropped = (
+                np.concatenate(ev_dropped).astype(np.int64) if ev_dropped
+                else np.zeros((0,), np.int64))
+            stats["events_dropped"] = int(res.events_dropped.sum())
     return res

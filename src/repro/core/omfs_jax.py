@@ -230,6 +230,7 @@ def default_knobs(cfg: SchedulerConfig,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("sched.queue_order")
 def queue_order(tbl: JobTable) -> Tuple[jax.Array, jax.Array]:
     """Snapshot the submitted queue: (order[J], eligible[J]).
 
@@ -303,6 +304,7 @@ def tier_occupancy(tbl: JobTable, n_tiers: int) -> jax.Array:
         jnp.clip(tbl.ckpt_tier, 0, n_tiers - 1), num_segments=n_tiers)
 
 
+@jax.named_scope("sched.victim_order")
 def victim_order(tbl: JobTable, cheap: bool = False) -> jax.Array:
     """Victim permutation.  Standard: ``(priority, run_start, id)`` —
     queues.running_victim_key.  ``cheap`` (the `omfs_cheap_victim` policy):
@@ -337,6 +339,7 @@ def select_victims(tbl: JobTable, evictable: jax.Array, idle: jax.Array,
     return planned, enough
 
 
+@jax.named_scope("sched.place_checkpoints")
 def place_checkpoints(cfg: SchedulerConfig, tbl: JobTable, ckpt: jax.Array,
                       order: Optional[jax.Array] = None,
                       ) -> Tuple[jax.Array, jax.Array]:
@@ -392,6 +395,7 @@ def _tiered(cfg: SchedulerConfig) -> bool:
     return cfg.cr_tiers is not None and cfg.cr_tiers.n_tiers > 1
 
 
+@jax.named_scope("sched.plan_evictions")
 def plan_evictions(cfg: SchedulerConfig, tbl: JobTable, evictable: jax.Array,
                    idle: jax.Array, cpus_needed: jax.Array,
                    cheap: bool = False, order: Optional[jax.Array] = None):
@@ -615,7 +619,8 @@ def make_omfs_pass(pass_depth: Optional[int] = None, incremental: bool = True,
                     elig = elig & (i < knobs.depth)
                 return _try_admit(cfg, ent, t, tbl, idx, elig,
                                   cheap_victims, knobs, vorder0)
-            return jax.lax.fori_loop(0, depth, body_ref, tbl)
+            with jax.named_scope("sched.admit"):
+                return jax.lax.fori_loop(0, depth, body_ref, tbl)
 
         usage0, nonp0, busy0 = running_usage(tbl, ent.shape[0])
 
@@ -676,8 +681,9 @@ def make_omfs_pass(pass_depth: Optional[int] = None, incremental: bool = True,
             busy = busy + grant
             return tbl, usage, nonp_usage, busy
 
-        tbl, _, _, _ = jax.lax.fori_loop(
-            0, depth, body, (tbl, usage0, nonp0, busy0))
+        with jax.named_scope("sched.admit"):
+            tbl, _, _, _ = jax.lax.fori_loop(
+                0, depth, body, (tbl, usage0, nonp0, busy0))
         return tbl
 
     return pass_fn
@@ -794,6 +800,7 @@ def stack_tables(tables, ents) -> Tuple[JobTable, jax.Array]:
 
 
 @partial(jax.jit, donate_argnums=(0,))
+@jax.named_scope("stream.insert_rows")
 def insert_rows(tbl: JobTable, slots: jax.Array, rows: JobTable,
                 valid: jax.Array) -> JobTable:
     """Segment-compaction scatter for the streaming engine: overwrite

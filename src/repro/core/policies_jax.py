@@ -97,7 +97,9 @@ def make_static_partition_pass(pass_depth: Optional[int] = None):
             usage = usage.at[ju].add(jnp.where(admit, jc, 0))
             return tbl, usage
 
-        tbl, _ = jax.lax.fori_loop(0, _depth(n, pass_depth), body, (tbl, usage0))
+        with jax.named_scope("sched.admit"):
+            tbl, _ = jax.lax.fori_loop(0, _depth(n, pass_depth), body,
+                                       (tbl, usage0))
         return tbl
 
     return pass_fn
@@ -125,8 +127,9 @@ def make_capping_pass(pass_depth: Optional[int] = None):
             grant = jnp.where(admit, jc, 0)
             return tbl, usage.at[ju].add(grant), busy + grant
 
-        tbl, _, _ = jax.lax.fori_loop(
-            0, _depth(n, pass_depth), body, (tbl, usage0, busy0))
+        with jax.named_scope("sched.admit"):
+            tbl, _, _ = jax.lax.fori_loop(
+                0, _depth(n, pass_depth), body, (tbl, usage0, busy0))
         return tbl
 
     return pass_fn
@@ -154,9 +157,10 @@ def make_fcfs_pass(pass_depth: Optional[int] = None):
             tbl = admit_job(tbl, idx, t, admit)
             return tbl, busy + jnp.where(admit, jc, 0), blocked
 
-        tbl, _, _ = jax.lax.fori_loop(
-            0, _depth(n, pass_depth), body,
-            (tbl, busy0, jnp.asarray(False)))
+        with jax.named_scope("sched.admit"):
+            tbl, _, _ = jax.lax.fori_loop(
+                0, _depth(n, pass_depth), body,
+                (tbl, busy0, jnp.asarray(False)))
         return tbl
 
     return pass_fn
@@ -194,7 +198,8 @@ def make_backfill_pass(estimate_error: float = 0.0, with_cr: bool = False,
         # arange on monolithic tables (rows sorted by id) and stable when the
         # streaming engine recycles slots out of id order
         key = jnp.where(running, est, BIG)
-        ordr = jnp.lexsort((tbl.jid, key))
+        with jax.named_scope("sched.queue_order"):
+            ordr = jnp.lexsort((tbl.jid, key))
         cum = idle + jnp.cumsum(jnp.where(running[ordr], tbl.cpus[ordr], 0))
         crossed = cum >= head_cpus
         reservation = jnp.where(
@@ -241,7 +246,9 @@ def make_backfill_pass(estimate_error: float = 0.0, with_cr: bool = False,
                 jnp.where(admit, 1, tbl.backfilled[idx])))
             return tbl, busy + jnp.where(admit, jc, 0)
 
-        tbl, _ = jax.lax.fori_loop(1, _depth(n, pass_depth), body, (tbl, busy))
+        with jax.named_scope("sched.admit"):
+            tbl, _ = jax.lax.fori_loop(1, _depth(n, pass_depth), body,
+                                       (tbl, busy))
         return tbl
 
     return pass_fn

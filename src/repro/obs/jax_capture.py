@@ -68,6 +68,7 @@ def event_flags(pre: JobTable, post: JobTable, t: jax.Array
     return flags, args
 
 
+@jax.named_scope("sched.capture")
 def capture_tick(pre: JobTable, post: JobTable, t: jax.Array, ring_size: int
                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One tick's ``(counts[E], ring[R, 3], dropped)`` — all int32, shapes
