@@ -319,11 +319,11 @@ def check_retrace(root: Path) -> List[Violation]:
             f"streaming segment runner compiled {n} times across segments "
             "— the segment start tick must stay traced (one program for "
             "the whole stream)"))
-    ins = cache_size(omfs_jax.insert_rows)
+    ins = cache_size(omfs_jax.insert_packed)
     if ins is not None and ins > 1:
         out.append(Violation(
             "retrace", str(root / OMFS_JAX), 1,
-            f"segment-boundary insert_rows compiled {ins} times — the "
+            f"segment-boundary insert_packed compiled {ins} times — the "
             "compaction scatter must be one fixed-shape program per "
             "capacity"))
 

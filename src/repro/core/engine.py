@@ -882,9 +882,8 @@ def simulate_stream(
         ring = (lossless_ring_size(capacity) if event_ring is None
                 else event_ring)
 
-    ent = omfs_jax.entitlements(users, config.cpu_total)
-    empty, _ = omfs_jax.table_from_jobs([], users, config.cpu_total, config)
-    tbl = omfs_jax.pad_table(empty, capacity)
+    tbl, ent = omfs_jax.table_from_jobs([], users, config.cpu_total, config,
+                                        rows=capacity)
 
     feed = iter(jobs)
     lookahead: Optional[Job] = None
@@ -895,22 +894,25 @@ def simulate_stream(
              "capacity": capacity}
 
     def boundary(tbl, note):
-        """Compact finished rows out, insert due arrivals; host-side.  The
-        round's counters go on ``note``, the boundary's annotation."""
+        """Compact finished rows out, insert due arrivals.  The work is
+        numpy on the read-back table; the padded arrival block, ``slots``
+        and ``valid`` cross to the device as one packed array, then one
+        `insert_packed` dispatch.  The round's counters go on ``note``, the
+        boundary's annotation."""
         with span("stream.read_back"):
             host = jax.device_get(tbl)
         with span("stream.compact"):
-            pad = np.asarray(omfs_jax.is_pad(host))
-            finished = np.isin(np.asarray(host.state),
-                               (int(omfs_jax.DONE), int(omfs_jax.KILLED))
-                               ) & ~pad
+            pad = omfs_jax.host_is_pad(host)
+            finished = np.isin(host.state,
+                               (omfs_jax.DONE, omfs_jax.KILLED)) & ~pad
             n_finished = 0
             if finished.any():
                 idx = np.flatnonzero(finished)
                 n_finished = idx.size
                 archived.append(
                     jax.tree_util.tree_map(lambda a: a[idx], host))
-            free = np.flatnonzero(finished | pad)
+            free_mask = finished | pad
+            free = np.flatnonzero(free_mask)
             stats["peak_live"] = max(stats["peak_live"],
                                      capacity - free.size)
             k = min(len(due), free.size)
@@ -922,20 +924,20 @@ def simulate_stream(
         if k == 0 and not n_finished:
             return tbl
         take, due[:] = due[:k], due[k:]
-        with span("stream.build"):
-            block, _ = omfs_jax.table_from_jobs(take, users,
-                                                config.cpu_total, config)
-            rows = omfs_jax.pad_table(block, capacity)
-        with span("stream.insert"):
+        with span("stream.build") as build:
             # arrivals fill the first k free slots; pad rows clear the rest
             # of the freed slots; occupied slots get a masked write-back.
             # `slots` is a permutation of arange(capacity) by construction.
-            slots = np.concatenate(
-                [free, np.setdiff1d(np.arange(capacity), free)])
+            slots = np.concatenate([free, np.flatnonzero(~free_mask)])
             valid = np.arange(capacity) < free.size
+            rows, _ = omfs_jax.table_from_jobs(take, users, config.cpu_total,
+                                               config, rows=capacity,
+                                               host=True)
+            packed = jax.device_put(omfs_jax.pack_insert(rows, slots, valid))
+            build.set_metadata(rows=k, h2d_bytes=packed.nbytes)
+        with span("stream.insert"):
             stats["inserted"] += k
-            return omfs_jax.insert_rows(tbl, jnp.asarray(slots, jnp.int32),
-                                        rows, jnp.asarray(valid))
+            return omfs_jax.insert_packed(tbl, packed)
 
     ev_counts: List[np.ndarray] = []
     ev_rings: List[np.ndarray] = []
@@ -997,16 +999,14 @@ def simulate_stream(
     with span("stream.extract"):
         stats["dropped"] = len(due)
         host = jax.device_get(tbl)
-        live = np.flatnonzero(~np.asarray(omfs_jax.is_pad(host)))
+        live = np.flatnonzero(~omfs_jax.host_is_pad(host))
         parts = archived + [
             jax.tree_util.tree_map(lambda a: a[live], host)]
-        merged_np = {
-            f: np.concatenate([np.asarray(getattr(p, f)) for p in parts])
-            for f in omfs_jax.JobTable._fields}
-        order = np.argsort(merged_np["jid"], kind="stable")
-        merged = omfs_jax.JobTable(**{
-            f: jnp.asarray(v[order], jnp.int32)
-            for f, v in merged_np.items()})
+        merged_np = jax.tree_util.tree_map(
+            lambda *cols: np.concatenate(cols), *parts)
+        order = np.argsort(merged_np.jid, kind="stable")
+        merged = jax.device_put(
+            jax.tree_util.tree_map(lambda a: a[order], merged_np))
         busy = (np.concatenate(busy_parts) if busy_parts
                 else np.zeros((0,), np.int32))
         res = EngineResult(policy=policy, backend="jax", config=config,

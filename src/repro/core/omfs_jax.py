@@ -60,6 +60,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.crcost import MAX_STATE_MIB
 from repro.core.types import JobClass, SchedulerConfig
@@ -140,7 +141,8 @@ class JobTable(NamedTuple):
 
 
 def table_from_jobs(jobs, users, cpu_total: int,
-                    config: Optional[SchedulerConfig] = None,
+                    config: Optional[SchedulerConfig] = None, *,
+                    rows: Optional[int] = None, host: bool = False,
                     ) -> Tuple[JobTable, jax.Array]:
     """Build ``(JobTable, entitled_cpus[U])`` from core.types objects.
 
@@ -150,18 +152,22 @@ def table_from_jobs(jobs, users, cpu_total: int,
     evaluated here with Python integers — the exact arithmetic the Python
     backend charges at runtime — so cross-backend bit-equality holds by
     construction.  ``config=None`` builds a free-C/R table (legacy callers).
+    ``rows`` pads the table to that many rows with inert pad rows, as
+    `pad_table` does.  The columns are built as int32 numpy arrays and cross
+    to the device in one ``jax.device_put``; ``host=True`` returns them
+    (and the entitlements) as numpy, untransferred.
     """
     uidx = {u.name: i for i, u in enumerate(users)}
     j = sorted(jobs, key=lambda x: x.id)
     n = len(j)
     cfg = config if config is not None else SchedulerConfig()
     n_tiers = cfg.n_cost_tiers
-    arr = lambda f, d=jnp.int32: jnp.asarray([f(x) for x in j], d)
+    arr = lambda f: np.asarray([f(x) for x in j], np.int32).reshape(n)
     # the [J, T] lattices: evaluated per (job, tier) with Python ints —
     # the exact arithmetic omfs._evict / _start charge at runtime
-    lat = lambda f: jnp.asarray(
+    lat = lambda f: np.asarray(
         [[f(x, k) for k in range(n_tiers)] for x in j],
-        jnp.int32).reshape(n, n_tiers)
+        np.int32).reshape(n, n_tiers)
     table = JobTable(
         jid=arr(lambda x: x.id),
         user=arr(lambda x: uidx[x.user]),
@@ -178,23 +184,30 @@ def table_from_jobs(jobs, users, cpu_total: int,
                                                 recurrent=True)),
         cost_restore_lat=lat(
             lambda x, k: cfg.restart_restore_cost(x.state_mib, k)),
-        state=jnp.full((n,), UNSUB, jnp.int32),
-        progress=jnp.zeros((n,), jnp.int32),
-        run_start=jnp.full((n,), -1, jnp.int32),
-        first_start=jnp.full((n,), -1, jnp.int32),
-        finish=jnp.full((n,), -1, jnp.int32),
-        n_preempt=jnp.zeros((n,), jnp.int32),
-        n_ckpt=jnp.zeros((n,), jnp.int32),
-        overhead=jnp.zeros((n,), jnp.int32),
+        state=np.full((n,), UNSUB, np.int32),
+        progress=np.zeros((n,), np.int32),
+        run_start=np.full((n,), -1, np.int32),
+        first_start=np.full((n,), -1, np.int32),
+        finish=np.full((n,), -1, np.int32),
+        n_preempt=np.zeros((n,), np.int32),
+        n_ckpt=np.zeros((n,), np.int32),
+        overhead=np.zeros((n,), np.int32),
         backfilled=arr(lambda x: int(x.backfilled)),
-        ckpt_tier=jnp.full((n,), -1, jnp.int32),
-        n_spill=jnp.zeros((n,), jnp.int32),
+        ckpt_tier=np.full((n,), -1, np.int32),
+        n_spill=np.zeros((n,), np.int32),
     )
-    return table, entitlements(users, cpu_total)
+    if rows is not None:
+        table = _pad(table, rows, np)
+    out = (table, _host_entitlements(users, cpu_total))
+    return out if host else jax.device_put(out)
+
+
+def _host_entitlements(users, cpu_total: int) -> np.ndarray:
+    return np.asarray([u.entitled_cpus(cpu_total) for u in users], np.int32)
 
 
 def entitlements(users, cpu_total: int) -> jnp.ndarray:
-    return jnp.asarray([u.entitled_cpus(cpu_total) for u in users], jnp.int32)
+    return jax.device_put(_host_entitlements(users, cpu_total))
 
 
 class Knobs(NamedTuple):
@@ -762,22 +775,34 @@ _PAD_VALUES = {"jid": int(BIG), "submit": int(BIG), "run_start": -1,
 
 def pad_table(tbl: JobTable, rows: int) -> JobTable:
     """Grow ``tbl`` to ``rows`` with inert pad rows (identity if equal)."""
+    return _pad(tbl, rows, jnp)
+
+
+def _pad(tbl: JobTable, rows: int, xp) -> JobTable:
+    """`pad_table` in array module ``xp``: ``jnp`` on the device, ``np`` on
+    the host."""
     n = tbl.cpus.shape[0]
     if rows == n:
         return tbl
     assert rows > n, f"cannot shrink table {n} -> {rows}"
     k = rows - n
     return JobTable(**{
-        f: jnp.concatenate(
+        f: xp.concatenate(
             [getattr(tbl, f),
-             jnp.full((k,) + getattr(tbl, f).shape[1:],
-                      _PAD_VALUES.get(f, 0), jnp.int32)])
+             xp.full((k,) + getattr(tbl, f).shape[1:],
+                     _PAD_VALUES.get(f, 0), xp.int32)])
         for f in JobTable._fields})
 
 
 def is_pad(tbl: JobTable) -> jax.Array:
     """Mask of inert pad rows (see ``_PAD_VALUES``)."""
     return (tbl.jid == BIG) & (tbl.submit == BIG)
+
+
+def host_is_pad(tbl: JobTable) -> np.ndarray:
+    """`is_pad` of a table read back to the host, in numpy."""
+    return ((tbl.jid == _PAD_VALUES["jid"])
+            & (tbl.submit == _PAD_VALUES["submit"]))
 
 
 def stack_tables(tables, ents) -> Tuple[JobTable, jax.Array]:
@@ -823,6 +848,30 @@ def insert_rows(tbl: JobTable, slots: jax.Array, rows: JobTable,
                       for f in JobTable._fields])
 
 
+def pack_insert(rows: JobTable, slots: np.ndarray,
+                valid: np.ndarray) -> np.ndarray:
+    """`insert_rows`' host-side arguments as one int32 ``[J, C]`` array:
+    the table's columns in field order (a ``[J, T]`` lattice takes T
+    columns), then ``slots``, then ``valid``.  The device pays per array
+    transferred, so the stream boundary sends this one (`insert_packed`)."""
+    return np.concatenate(
+        [c.reshape(len(slots), -1) for c in rows]
+        + [slots[:, None], valid[:, None]], axis=1, dtype=np.int32)
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def insert_packed(tbl: JobTable, packed: jax.Array) -> JobTable:
+    """`insert_rows` with its arguments packed by `pack_insert`."""
+    cols, at = {}, 0
+    for f in JobTable._fields:
+        col = getattr(tbl, f)
+        width = col.shape[1] if col.ndim == 2 else 1
+        cols[f] = packed[:, at:at + width].reshape(col.shape)
+        at += width
+    return insert_rows(tbl, packed[:, at], JobTable(**cols),
+                       packed[:, at + 1] != 0)
+
+
 def signature_from_table(tbl: JobTable):
     """Same shape as SimResult.schedule_signature() for equivalence tests."""
     t = jax.device_get(tbl)
@@ -835,7 +884,6 @@ def signature_from_table(tbl: JobTable):
 
 def tables_equal(a: JobTable, b: JobTable) -> bool:
     """Fast whole-table schedule equality (the fields of the signature)."""
-    import numpy as np
     fields = ("state", "first_start", "finish", "progress", "n_preempt",
               "n_ckpt")
     a, b = jax.device_get(a), jax.device_get(b)

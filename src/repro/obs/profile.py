@@ -12,8 +12,10 @@ set per round, nested as indented::
       stream.boundary      the host boundary (finished, inserted, deferred, live)
         stream.read_back   the blocking device_get of the table
         stream.compact     find and archive finished rows
-        stream.build       table_from_jobs + pad_table of the arrivals
-        stream.insert      slots, valid, insert_rows
+        stream.build       the padded arrival block, slots and valid, built
+                           on the host, packed, put on the device in one
+                           transfer (rows, h2d_bytes)
+        stream.insert      the insert_packed dispatch
       stream.segment       the segment (t0, ticks, fresh)
         stream.dispatch    the runner call
         stream.wait        where the host blocks on the segment

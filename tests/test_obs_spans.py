@@ -115,6 +115,23 @@ def test_stream_spans_nest_per_round_with_counters(traced):
     assert [s.name for s in spans].count("stream.extract") == 1
 
 
+def test_stream_build_counts_rows_and_bytes(traced):
+    """Each ``stream.build`` carries the arrivals it built and the bytes it
+    put on the device: the padded block, ``slots`` and ``valid``, packed."""
+    _, res, _, spans = traced
+    builds = [s for s in spans if s.name == "stream.build"]
+    assert builds and all(set(s.args) == {"rows", "h2d_bytes"}
+                          for s in builds)
+    assert sum(s.args["rows"] for s in builds) == \
+        res.stream_stats["inserted"]
+    users, _, _ = _conveyor()
+    cfg = SchedulerConfig(cpu_total=16, quantum=2, cr_overhead=1)
+    block, _ = omfs_jax.table_from_jobs([], users, cfg.cpu_total, cfg,
+                                        rows=CAPACITY, host=True)
+    want = 4 * CAPACITY * (sum(c[0].size for c in block) + 2)
+    assert {s.args["h2d_bytes"] for s in builds} == {want}
+
+
 def test_stream_hook_keeps_its_three_sections(traced):
     _, res, timers, _ = traced
     snap = timers.snapshot()
@@ -167,6 +184,19 @@ def test_insert_rows_carries_its_scope():
                            "sched.plan_evictions", "sched.victim_order",
                            "sched.place_checkpoints", "sched.capture",
                            "stream.insert_rows"}
+
+
+def test_insert_packed_carries_the_insert_scope():
+    """The stream boundary's insert (`insert_packed`) runs `insert_rows`
+    inside its program, so its ops keep the ``stream.insert_rows`` scope."""
+    users = [User("A", 50.0)]
+    cfg = SchedulerConfig(cpu_total=16, quantum=2)
+    tbl, _ = omfs_jax.table_from_jobs([], users, cfg.cpu_total, cfg, rows=8)
+    block, _ = omfs_jax.table_from_jobs([], users, cfg.cpu_total, cfg,
+                                        rows=8, host=True)
+    packed = omfs_jax.pack_insert(block, np.arange(8), np.ones(8, bool))
+    lowered = omfs_jax.insert_packed.lower(tbl, jax.device_put(packed))
+    assert "stream.insert_rows" in _compiled_scopes(lowered)
 
 
 # -- the reader, on hand-made events (ns; one device unless named) --------
