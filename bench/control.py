@@ -20,13 +20,14 @@ from pathlib import Path
 BENCH = Path(__file__).resolve().parent
 sys.path.insert(0, str(BENCH))
 
-from reference.sched_ref import mismatches  # noqa: E402
+from parts import reference_of  # noqa: E402
 
 
 def control_readings(config: dict, work: dict, seed: int,
                      seconds: float) -> dict:
     """The numbers a run's check compares, read from the control."""
     from reference.runs import references
+    mismatches = reference_of(config).mismatches
     refs = references(config, work, seed, seconds)
     ctrl = references(config, work, seed, seconds, ignore_quantum=True)
     table = busy = counts = 0

@@ -8,17 +8,19 @@ program's output and the reference's verdict on it.
 """
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time
 from contextlib import contextmanager
-from typing import Callable, Dict, List, Optional
+from types import ModuleType
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import numpy as np
 
-from reference.runs import references, sweep_cells, window_rounds
-from reference.sched_ref import COMPARED, mismatches
-from traffic.generator import generate
+from parts import reference_of, shares_of, traffic_of
+from reference.runs import (batch_draw, references, sweep_cells,
+                            window_rounds)
 from repro.core import engine, omfs_jax
 from repro.core.crcost import MIB, CRCostModel, TieredCRCostModel
 from repro.core.types import Job, JobClass, SchedulerConfig, User
@@ -85,7 +87,8 @@ class RoundClock:
 
 def scheduler_config(config: dict) -> SchedulerConfig:
     """The program's `SchedulerConfig` for a configuration file: a flat
-    cost per checkpoint, and the C/R tiers where the file has them."""
+    cost per checkpoint, the C/R tiers where the file has them, then each
+    key of its ``scheduler`` block as it stands."""
     kw = {}
     if config.get("cr_tiers"):
         tiers = config["cr_tiers"]
@@ -94,14 +97,34 @@ def scheduler_config(config: dict) -> SchedulerConfig:
                                        if k != "capacity_mib"})
                         for t in tiers),
             capacity_mib=tuple(int(t["capacity_mib"]) for t in tiers))
-    return SchedulerConfig(cpu_total=int(config["cpu_total"]),
-                           quantum=int(config["quantum"]),
-                           cr_overhead=int(config["cr_overhead"]), **kw)
+    kw.update(cpu_total=int(config["cpu_total"]),
+              quantum=int(config["quantum"]),
+              cr_overhead=int(config["cr_overhead"]))
+    fields = {f.name for f in dataclasses.fields(SchedulerConfig)}
+    for key, value in config.get("scheduler", {}).items():
+        if key not in fields:
+            raise ValueError(f"configuration {config.get('name')!r}: "
+                             f"scheduler key {key!r} is not a field of "
+                             f"SchedulerConfig")
+        kw[key] = value
+    return SchedulerConfig(**kw)
 
 
 def users_of(config: dict) -> List[User]:
-    n = int(config["tenants"])
-    return [User(f"u{i}", 100.0 / n) for i in range(n)]
+    return [User(f"u{i}", s) for i, s in enumerate(shares_of(config))]
+
+
+class Parts(NamedTuple):
+    """What a configuration file brings to a run (`parts`)."""
+    generate: Callable
+    reference: ModuleType
+    cfg: SchedulerConfig
+    users: List[User]
+
+
+def parts_of(config: dict) -> Parts:
+    return Parts(traffic_of(config), reference_of(config),
+                 scheduler_config(config), users_of(config))
 
 
 def to_jobs(cols: Dict[str, np.ndarray]) -> List[Job]:
@@ -114,9 +137,9 @@ def to_jobs(cols: Dict[str, np.ndarray]) -> List[Job]:
                 cols["jclass"], cols["submit"], cols["state_mib"]))]
 
 
-def table_columns(tbl) -> Dict[str, np.ndarray]:
+def table_columns(tbl, compared: Sequence[str]) -> Dict[str, np.ndarray]:
     host = jax.device_get(tbl)
-    return {f: np.asarray(getattr(host, f)) for f in ("jid",) + COMPARED}
+    return {f: np.asarray(getattr(host, f)) for f in ("jid", *compared)}
 
 
 def device_peak(devices) -> Optional[int]:
@@ -128,7 +151,9 @@ def device_peak(devices) -> Optional[int]:
 
 class Tracer:
     """Profiles ``seconds`` of the window into ``trace_dir``, from
-    ``delay`` seconds after `start`.
+    ``delay`` seconds after `start`, or from `begin` where that comes
+    first: the drivers call it as the window's last round starts, so that
+    a window shorter than ``delay`` still leaves a trace.
 
     A round of the large cells runs for seconds, and the device records an
     event per operation of its loops, so the trace is started and stopped
@@ -144,21 +169,28 @@ class Tracer:
         self.lock = threading.Lock()
         self.state = "idle"
 
-    def start(self) -> None:
-        if self.dir is None or self.timers:
-            return
-        for at, fn in ((self.delay, self._begin),
-                       (self.delay + self.seconds, self.stop)):
-            self.timers.append(threading.Timer(at, fn))
-            self.timers[-1].start()
+    def _after(self, seconds: float, fn: Callable[[], None]) -> None:
+        timer = threading.Timer(seconds, fn)
+        self.timers.append(timer)
+        timer.start()
 
-    def _begin(self) -> None:
+    def start(self) -> None:
         with self.lock:
-            if self.state == "idle":
+            if self.dir is not None and not self.timers:
+                self._after(self.delay, self.begin)
+
+    def begin(self) -> None:
+        if self.state != "idle":
+            # begun already: `stop` holds the lock while it writes the
+            # trace, which can outlast the window, and no round waits on it
+            return
+        with self.lock:
+            if self.dir is not None and self.state == "idle":
                 opts = jax.profiler.ProfileOptions()
                 opts.python_tracer_level = 0     # annotations only
                 jax.profiler.start_trace(self.dir, profiler_options=opts)
                 self.state = "running"
+                self._after(self.seconds, self.stop)
 
     def stop(self) -> None:
         with self.lock:
@@ -167,10 +199,12 @@ class Tracer:
             self.state = "done"
 
     def close(self) -> None:
-        for t in self.timers:
+        self.stop()
+        with self.lock:
+            timers = list(self.timers)
+        for t in timers:
             t.cancel()
             t.join()
-        self.stop()
 
 
 def round_counts(submit: np.ndarray, seg: int, rounds: int) -> np.ndarray:
@@ -184,8 +218,7 @@ def run_stream(config: dict, work: dict, seed: int, seconds: float,
     the next ``warm_rounds`` let the running set turn over; all of that is
     set-up, and runs every program the window runs.  The window is every
     later round."""
-    cfg = scheduler_config(config)
-    users = users_of(config)
+    generate, ref_mod, cfg, users = parts_of(config)
     seg = int(work["segment_len"])
     warm_rounds = int(work["warm_rounds"])
     rounds = window_rounds(work, seconds)
@@ -215,6 +248,8 @@ def run_stream(config: dict, work: dict, seed: int, seconds: float,
             if r == 2 + warm_rounds:
                 compiles.window_open = True
                 tracer.start()
+            if r == total:
+                tracer.begin()
 
         clock = RoundClock(on_round)
         try:
@@ -244,7 +279,7 @@ def run_stream(config: dict, work: dict, seed: int, seconds: float,
         "failed": int(res.stream_stats["deferrals"]),
     }
     out["memory_peak_bytes"] = device_peak(jax.local_devices())
-    program = table_columns(res.table)
+    program = table_columns(res.table, ref_mod.COMPARED)
     busy = np.asarray(res.busy)
     stats = dict(res.stream_stats)
     del res
@@ -253,7 +288,7 @@ def run_stream(config: dict, work: dict, seed: int, seconds: float,
 
     [(ref, ref_stats)] = references(config, work, seed, seconds)
     out["checks"] = {
-        "table_mismatches": (mismatches(program, ref.table()), 0),
+        "table_mismatches": (ref_mod.mismatches(program, ref.table()), 0),
         "busy_mismatches": (int((busy != np.asarray(ref.busy)).sum())
                             + abs(busy.size - len(ref.busy)), 0),
         "stream_count_mismatches": (sum(
@@ -266,10 +301,10 @@ def run_stream(config: dict, work: dict, seed: int, seconds: float,
 def run_batch(config: dict, work: dict, seed: int, seconds: float,
               trace_dir: Optional[str], t_process: float) -> dict:
     """A fixed number of `simulate_batch` calls over the knob grid, each
-    on its own draw of the traffic; a first call on another draw is
-    set-up."""
-    cfg = scheduler_config(config)
-    users = users_of(config)
+    on its own draw of the traffic (`batch_draw`: every draw fills the
+    job table, so one program serves them all); a first call on another
+    draw is set-up."""
+    generate, ref_mod, cfg, users = parts_of(config)
     horizon = int(work["horizon"])
     grid = sweep_cells(work)
     n_calls = window_rounds(work, seconds)
@@ -284,18 +319,20 @@ def run_batch(config: dict, work: dict, seed: int, seconds: float,
     outputs = []
     with CompileLog() as compiles:
         warm = engine.simulate_batch(
-            cells_of(generate(config, work, seed, horizon, stream=1 << 20)),
+            cells_of(batch_draw(generate, config, work, seed, 1 << 20)),
             cfg, horizon)
         jax.block_until_ready([r.table for r in warm])
         del warm
-        inputs = [cells_of(generate(config, work, seed, horizon, stream=r))
+        inputs = [cells_of(batch_draw(generate, config, work, seed, r))
                   for r in range(n_calls)]
+        # the trace holds the window's last call whole
         tracer = Tracer(trace_dir, float(work["trace_seconds"]))
         compiles.window_open = True
         t0 = time.perf_counter()
-        tracer.start()
         try:
-            for cells in inputs:
+            for i, cells in enumerate(inputs):
+                if i == n_calls - 1:
+                    tracer.begin()
                 start = time.perf_counter()
                 with jax.profiler.TraceAnnotation("bench.simulate_batch"):
                     results = engine.simulate_batch(cells, cfg, horizon)
@@ -308,7 +345,7 @@ def run_batch(config: dict, work: dict, seed: int, seconds: float,
     out = {
         "setup_s": t0 - t_process,
         "window_s": calls[-1]["end"] - t0,
-        "cell_ticks": n_calls * len(grid) * horizon,
+        "ticks": n_calls * len(grid) * horizon,
         "round_s": [c["end"] - c["start"] for c in calls],
         "compile_s": compiles.before_s,
         "compiles_in_window": compiles.inside,
@@ -318,18 +355,18 @@ def run_batch(config: dict, work: dict, seed: int, seconds: float,
     n_dev = len(jax.local_devices())
     spread = min(len(x.table.cpus.sharding.device_set)
                  for results in outputs for x in results)
-    checked = [[(table_columns(x.table), np.asarray(x.busy)) for x in results]
-               for results in outputs]
+    checked = [[(table_columns(x.table, ref_mod.COMPARED), np.asarray(x.busy))
+                for x in results] for results in outputs]
     del outputs
     out["preemptions"] = int(sum(t["n_preempt"].sum() for c in checked
                                  for t, _ in c))
-    out["ticks_total"] = out["cell_ticks"]
+    out["ticks_total"] = out["ticks"]
 
     bad_cells, table_bad, busy_bad = 0, 0, 0
     refs = references(config, work, seed, seconds)
     for (program, busy), (ref, _) in zip(
             (c for per_cell in checked for c in per_cell), refs):
-        tb = mismatches(program, ref.table())
+        tb = ref_mod.mismatches(program, ref.table())
         bb = int((busy != np.asarray(ref.busy)).sum())
         table_bad += tb
         busy_bad += bb

@@ -24,6 +24,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from parts import shares_of
+
 UNSUB, PENDING, RUNNING, DONE, KILLED = 0, 1, 2, 3, 4
 NONP, PREEMPT, CKPT = 0, 1, 2
 #: the columns a run is compared on, in the program's names
@@ -123,8 +125,8 @@ class RefSim:
             raise NotImplementedError(f"no reference for policy {policy!r}")
         self.policy = policy
         self.cpu_total = int(config["cpu_total"])
-        n_ten = int(config["tenants"])
-        self.ent = [int((100.0 / n_ten) / 100.0 * self.cpu_total)] * n_ten
+        self.ent = [int(s / 100.0 * self.cpu_total)
+                    for s in shares_of(config)]
         self.costs = Costs(config)
         self.quantum = 0 if ignore_quantum else int(quantum)
         self.depth = int(depth)

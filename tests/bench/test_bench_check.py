@@ -7,6 +7,7 @@ on the chip, with the cells' job tables cut to 300 rows and short
 windows."""
 import json
 import sys
+import threading
 import time
 from pathlib import Path
 
@@ -17,6 +18,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT / "bench"))
 
 import run  # noqa: E402
+from drive import Tracer  # noqa: E402
 from control import control_readings  # noqa: E402
 from repro.core import engine, omfs_jax, policies_jax  # noqa: E402
 
@@ -132,3 +134,30 @@ def test_broken_timed_path_is_not_correct(cell, fault, monkeypatch,
     FAULTS[fault](monkeypatch, cell)
     res = drive(cell)
     assert not res["correct"], res["checks"]
+
+
+@pytest.mark.parametrize("cell", ["hpc10k.live", "hpc10k.sweep4"])
+def test_trace_begins_by_the_last_round(cell, tmp_path):
+    """A window that ends before ``trace_start_s`` still leaves a trace:
+    the tracer begins as the window's last round starts."""
+    c, config, work = tiny(cell)
+    work["trace_start_s"] = 3600.0
+    res = run.run_cell(c, config, work, run.metrics_for(MANIFEST, cell, True),
+                       SEED, 1.0, True, time.perf_counter(),
+                       trace_dir=str(tmp_path))
+    assert res["correct"], res["checks"]
+    assert res["info"]["window_s"] < work["trace_start_s"]
+    assert list(tmp_path.rglob("*.xplane.pb"))
+
+
+def test_begin_never_waits_on_a_stop():
+    """The last round's `drive.Tracer.begin` returns at once while `stop`
+    holds the lock to write a trace that has run."""
+    tracer = Tracer("unused", 1.0)
+    tracer.state = "running"
+    with tracer.lock:
+        last_round = threading.Thread(target=tracer.begin)
+        last_round.start()
+        last_round.join(timeout=10)
+        assert not last_round.is_alive()
+    assert tracer.state == "running" and not tracer.timers
