@@ -35,6 +35,7 @@ from jax.sharding import Mesh, PartitionSpec
 from repro.core import omfs_jax, policies_jax
 from repro.core.baselines import ALL_BASELINES
 from repro.core.omfs import Decision, cheap_victim_pass, scheduler_pass
+from repro.core.placement import gang_nodes
 from repro.core.types import ClusterState, Job, JobState, SchedulerConfig, User
 
 PythonPolicy = Callable[[ClusterState], List[Decision]]
@@ -76,6 +77,23 @@ register_policy(
                                                     cheap_victims=True))
 for _name, _factory in policies_jax.JAX_BASELINES.items():
     register_policy(_name, ALL_BASELINES[_name], _factory)
+
+
+#: the policies that place jobs on nodes (`SchedulerConfig.node_size`)
+NODE_POLICIES = ("omfs", "omfs_cheap_victim")
+
+
+def _check_nodes(config: SchedulerConfig, policies) -> None:
+    """With ``node_size`` set, only the OMFS passes place jobs on nodes
+    (the fused kernel does not either: `omfs_jax.make_omfs_pass`); every
+    other policy would count GPUs as one pool, so it raises instead."""
+    if config.node_size is None:
+        return
+    for p in policies:
+        if isinstance(p, str) and p not in NODE_POLICIES:
+            raise NotImplementedError(
+                f"policy {p!r} does not place jobs on nodes; with "
+                f"SchedulerConfig.node_size set use one of {NODE_POLICIES}")
 
 
 def _resolve_python(policy: Union[str, PythonPolicy]) -> PythonPolicy:
@@ -325,6 +343,7 @@ class EngineResult:
             preempt = sum(j.n_preemptions for j in jobs)
             ckpt = sum(j.n_checkpoints for j in jobs)
             spills = sum(j.n_spills for j in jobs)
+            frag = sum(j.n_frag for j in jobs)
             killed = sum(1 for j in jobs if j.state == JobState.KILLED)
             done = sum(1 for j in jobs if j.state == JobState.DONE)
             was_killed = np.asarray(
@@ -339,6 +358,7 @@ class EngineResult:
             preempt = int(t.n_preempt.sum())
             ckpt = int(t.n_ckpt.sum())
             spills = int(t.n_spill.sum())
+            frag = 0 if t.n_frag is None else int(t.n_frag.sum())
             killed = int((t.state == omfs_jax.KILLED).sum())
             done = int((t.state == omfs_jax.DONE).sum())
             was_killed = np.asarray(t.state) == omfs_jax.KILLED
@@ -362,6 +382,9 @@ class EngineResult:
             "preemptions": preempt,
             "checkpoints": ckpt,
             "spills": spills,        # checkpoints placed beyond the fast tier
+            # admissions by eviction though idle GPUs sufficed: no node
+            # could take the job (always 0 without node_size)
+            "frag_evict_admits": frag,
             "killed": killed,
             "done": done,
         }
@@ -400,11 +423,14 @@ def simulate(
     """
     name = policy if isinstance(policy, str) else getattr(
         policy, "__name__", "custom")
+    _check_nodes(config, [policy])
 
     if backend == "python":
         pol = _resolve_python(policy)
         state = ClusterState(config=config, users={u.name: u for u in users})
         for j in sorted(jobs, key=lambda x: x.id):
+            if config.node_size is not None:
+                gang_nodes(j.cpus, config.node_size)
             j = j.clone()
             j.state = JobState.UNSUBMITTED
             state.jobs[j.id] = j
@@ -529,6 +555,7 @@ def simulate_matrix(
     unknown = [n for n in names if n not in POLICIES]
     if unknown:
         raise ValueError(f"unknown policies {unknown}; known: {sorted(POLICIES)}")
+    _check_nodes(config, names)
     pass_fns = tuple(POLICIES[n].jax_factory(pass_depth) for n in names)
     tbl, ent = omfs_jax.table_from_jobs(jobs, users, config.cpu_total, config)
     if tbl.cpus.shape[0] == 0:
@@ -680,6 +707,7 @@ def simulate_batch(
     unknown = [n for n in names if n not in POLICIES]
     if unknown:
         raise ValueError(f"unknown policies {unknown}; known: {sorted(POLICIES)}")
+    _check_nodes(config, names)
     # Per-cell depth rides the knobs (traced masking), but the fori_loop
     # trip count is static: when EVERY cell caps pass_depth, truncate the
     # compiled loop at the batch-wide max.  Iterations past a cell's own
@@ -844,7 +872,9 @@ def simulate_stream(
     (queue/victim tie-breaks) rides the table's ``jid`` column, not row
     position.  When slots run out, surplus arrivals are DEFERRED to a
     later boundary (they arrive late, like a submit-rate-limited
-    front-end); ``stream_stats["deferrals"]`` counts those events.
+    front-end); ``stream_stats["deferrals"]`` counts those events, and
+    ``stream_stats["frag_evict_admits"]`` the admissions that evicted
+    although enough GPUs sat idle (`SchedulerConfig.node_size`).
 
     Jobs whose ``submit_time >= horizon`` are left in the iterator and do
     not appear in the result table (the monolithic run keeps them as
@@ -873,6 +903,7 @@ def simulate_stream(
     if not isinstance(policy, str) or policy not in POLICIES:
         raise ValueError(
             f"unknown policy {policy!r}; known: {sorted(POLICIES)}")
+    _check_nodes(config, [policy])
     from repro.obs.profile import span
     pass_fn = POLICIES[policy].jax_factory(pass_depth)
 
@@ -1009,6 +1040,8 @@ def simulate_stream(
             jax.tree_util.tree_map(lambda a: a[order], merged_np))
         busy = (np.concatenate(busy_parts) if busy_parts
                 else np.zeros((0,), np.int32))
+        stats["frag_evict_admits"] = (0 if merged_np.n_frag is None
+                                      else int(merged_np.n_frag.sum()))
         res = EngineResult(policy=policy, backend="jax", config=config,
                            table=merged, busy=busy, stream_stats=stats)
         if record_events:

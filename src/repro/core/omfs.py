@@ -17,12 +17,18 @@ DESIGN.md):
   our (beyond-paper, default-off) refinements.
 * line 34: evicted non-checkpointable jobs are dropped (killed), unless
   ``drop_killed=False`` (restart-from-zero re-queue).
+
+With ``SchedulerConfig.node_size`` set, a job also needs a place on the
+nodes (`core.placement`, DESIGN.md §Node placement): line 26 admits on
+idle only where a placement on free capacity exists, and the eviction
+path takes the node-aware victim prefix instead of the counting loop.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
+from repro.core.placement import fit_free, node_free, plan_on_nodes
 from repro.core.queues import cheap_victim_key, sorted_pending, sorted_victims
 from repro.core.types import ClusterState, Job, JobClass, JobState
 
@@ -39,7 +45,7 @@ class Decision:
     killed: List[int] = field(default_factory=list)
 
 
-def _start(state: ClusterState, job: Job) -> None:
+def _start(state: ClusterState, job: Job, node: Optional[int] = None) -> None:
     if job.n_checkpoints > 0:
         # transparent restore from the latest snapshot: charge the
         # size-dependent read cost of the tier the snapshot was PLACED on
@@ -50,6 +56,8 @@ def _start(state: ClusterState, job: Job) -> None:
     # the restore consumes the snapshot: its tier slot frees for the next
     # victim (matches omfs_jax.admit_job clearing ckpt_tier)
     job.ckpt_tier = -1
+    if node is not None:
+        job.node = node
     job.state = JobState.RUNNING
     job.run_start = state.time
     if job.first_start < 0:
@@ -130,9 +138,17 @@ def runner(state: ClusterState, job: Job, *,
         dec.reason = "non-preemptible exceeds entitlement (line 23)"
         return dec                                            # lines 24-25
 
-    # line 26: enough idle resources -> run anyways (even over entitlement)
-    if state.cpu_idle > job.cpus:
-        _start(state, job)
+    # line 26: enough idle resources -> run anyways (even over entitlement);
+    # on nodes, only where the idle GPUs hold a placement
+    place = None
+    if cfg.node_size is not None:
+        running = [(j.id, j.node, j.cpus) for j in state.running_jobs()]
+        if state.cpu_idle > job.cpus:
+            place = fit_free(node_free(running, cfg.n_nodes, cfg.node_size),
+                             job.cpus, cfg.node_size)
+    if state.cpu_idle > job.cpus and (cfg.node_size is None
+                                      or place is not None):
+        _start(state, job, place)
         dec.admitted, dec.reason = True, "idle resources (line 26)"
         return dec                                            # line 27 (goto 37)
 
@@ -152,21 +168,32 @@ def runner(state: ClusterState, job: Job, *,
     if cfg.avoid_self_eviction:                               # beyond paper
         victims = [v for v in victims if v.user != job.user]
 
-    freed = 0
-    planned: List[Job] = []
-    for v in victims:                                         # line 32 loop
-        if state.cpu_idle + freed >= job.cpus:
-            break
-        planned.append(v)
-        freed += v.cpus
-    if state.cpu_idle + freed < job.cpus:
+    if cfg.node_size is None:
+        freed = 0
+        planned: List[Job] = []
+        for v in victims:                                     # line 32 loop
+            if state.cpu_idle + freed >= job.cpus:
+                break
+            planned.append(v)
+            freed += v.cpus
+        enough = state.cpu_idle + freed >= job.cpus
+    else:
+        plan = plan_on_nodes(running, {v.id: r for r, v in enumerate(victims)},
+                             cfg.n_nodes, cfg.node_size, job.cpus)
+        enough = plan is not None
+        if enough:
+            place, ids = plan
+            planned = [state.jobs[i] for i in ids]
+    if not enough:
         # not enough evictable capacity (all within quantum): wait
         dec.reason = "insufficient evictable capacity (quantum)"
         return dec
 
+    if state.cpu_idle > job.cpus:
+        job.n_frag += 1       # idle GPUs enough, but no node could take it
     for v in planned:
         _evict(state, v, dec)                                 # lines 33-36
-    _start(state, job)                                        # lines 37-38
+    _start(state, job, place)                                 # lines 37-38
     dec.admitted = True
     dec.reason = "entitled, evicted to fit (lines 31-38)" if planned else \
         "entitled, idle exactly sufficient (lines 31-38)"

@@ -52,6 +52,15 @@ the admit fast path stays O(1) — and `admit_job` charges the restore cost
 of the *placed* tier, then frees the slot.  Sizes may change at runtime
 via `update_state_mib` (O(1) scatters recomputing the lattice rows with
 the same arithmetic, no re-trace of the jitted scan).
+
+Node placement (`SchedulerConfig.node_size`, DESIGN.md §Node placement):
+the ``node`` column records the first node of each job's latest
+placement.  The OMFS pass carries the free GPUs of every node (``[N]``)
+beside the per-user usage: the idle-admit fast path admits only where
+`fit_free` finds a place on free capacity, and the eviction branch takes
+the node-aware victim prefix (`plan_node_evictions`).  With ``node_size``
+None the table has no ``node`` and ``n_frag`` columns (None), so every
+program and transfer is what it is without nodes.
 """
 from __future__ import annotations
 
@@ -63,6 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.crcost import MAX_STATE_MIB
+from repro.core.placement import gang_nodes
 from repro.core.types import JobClass, SchedulerConfig
 
 # JobState encoding (matches types.JobState)
@@ -112,6 +122,13 @@ class JobTable(NamedTuple):
     backfilled: jax.Array  # int32 0/1: ever admitted by queue-jumping
     ckpt_tier: jax.Array   # int32 tier holding the latest snapshot (-1: none)
     n_spill: jax.Array     # int32 checkpoints placed beyond the fast tier
+    # With ``node_size`` set (None otherwise: a table without nodes holds,
+    # moves and traces the columns above and nothing more):
+    node: Optional[jax.Array] = None    # int32 first node of the latest
+    #   placement (-1: never started); completion and eviction keep it
+    n_frag: Optional[jax.Array] = None  # int32 admissions by eviction
+    #   although more GPUs than the job's were idle: no node could take it
+    #   (the fragmentation counter ``frag_evict_admits`` is its sum)
 
     # Legacy two-column accessors, kept as read-only VIEWS over the lattice
     # during the [J, T] migration (DESIGN.md §Cost lattice).  ``...``
@@ -153,9 +170,11 @@ def table_from_jobs(jobs, users, cpu_total: int,
     backend charges at runtime — so cross-backend bit-equality holds by
     construction.  ``config=None`` builds a free-C/R table (legacy callers).
     ``rows`` pads the table to that many rows with inert pad rows, as
-    `pad_table` does.  The columns are built as int32 numpy arrays and cross
-    to the device in one ``jax.device_put``; ``host=True`` returns them
-    (and the entitlements) as numpy, untransferred.
+    `pad_table` does.  The columns are built as int32 numpy arrays and
+    cross to the device in one ``jax.device_put``; ``host=True`` returns
+    them (and the entitlements) as numpy, untransferred.  With
+    ``config.node_size`` set, a job of a size no node placement can hold
+    raises (`placement.gang_nodes`).
     """
     uidx = {u.name: i for i, u in enumerate(users)}
     j = sorted(jobs, key=lambda x: x.id)
@@ -163,6 +182,11 @@ def table_from_jobs(jobs, users, cpu_total: int,
     cfg = config if config is not None else SchedulerConfig()
     n_tiers = cfg.n_cost_tiers
     arr = lambda f: np.asarray([f(x) for x in j], np.int32).reshape(n)
+    cpus = arr(lambda x: x.cpus)
+    nodes = cfg.node_size is not None
+    if nodes:
+        for c in np.unique(cpus):
+            gang_nodes(int(c), cfg.node_size)
     # the [J, T] lattices: evaluated per (job, tier) with Python ints —
     # the exact arithmetic omfs._evict / _start charge at runtime
     lat = lambda f: np.asarray(
@@ -171,7 +195,7 @@ def table_from_jobs(jobs, users, cpu_total: int,
     table = JobTable(
         jid=arr(lambda x: x.id),
         user=arr(lambda x: uidx[x.user]),
-        cpus=arr(lambda x: x.cpus),
+        cpus=cpus,
         work=arr(lambda x: x.work),
         priority=arr(lambda x: x.priority),
         jclass=arr(lambda x: int(x.job_class)),
@@ -195,6 +219,8 @@ def table_from_jobs(jobs, users, cpu_total: int,
         backfilled=arr(lambda x: int(x.backfilled)),
         ckpt_tier=np.full((n,), -1, np.int32),
         n_spill=np.zeros((n,), np.int32),
+        node=np.full((n,), -1, np.int32) if nodes else None,
+        n_frag=np.zeros((n,), np.int32) if nodes else None,
     )
     if rows is not None:
         table = _pad(table, rows, np)
@@ -514,6 +540,145 @@ def apply_evictions(cfg: SchedulerConfig, t: jax.Array, tbl: JobTable,
 
 
 # ---------------------------------------------------------------------------
+# Node placement (cfg.node_size; DESIGN.md §Node placement): the twins of
+# `core.placement`'s node functions over the JobTable
+# ---------------------------------------------------------------------------
+
+
+def _cover(cfg: SchedulerConfig, tbl: JobTable, multi: jax.Array,
+           value: jax.Array) -> jax.Array:
+    """Per node ``[N]``, the sum of ``value`` over the ``multi`` rows whose
+    whole-node range covers it (a difference array: O(J) scatter, O(N)
+    cumsum).  Running ranges never overlap, so at most one row counts."""
+    n = cfg.n_nodes
+    lo = jnp.where(multi, tbl.node, n)
+    hi = jnp.where(multi, tbl.node + tbl.cpus // cfg.node_size, n)
+    v = jnp.where(multi, value, 0)
+    diff = jnp.zeros((n + 1,), jnp.int32).at[lo].add(v).at[hi].add(-v)
+    return jnp.cumsum(diff)[:n]
+
+
+def node_free(cfg: SchedulerConfig, tbl: JobTable) -> jax.Array:
+    """Free GPUs per node ``[N]``, counted from the RUNNING rows."""
+    g, n = cfg.node_size, cfg.n_nodes
+    running = tbl.state == RUNNING
+    single = running & (tbl.cpus <= g)
+    used = jax.ops.segment_sum(jnp.where(single, tbl.cpus, 0),
+                               jnp.where(single, tbl.node, n),
+                               num_segments=n + 1)[:n]
+    return g - used - g * _cover(cfg, tbl, running & (tbl.cpus > g),
+                                 jnp.ones_like(tbl.cpus))
+
+
+@jax.named_scope("sched.place_nodes")
+def fit_free(cfg: SchedulerConfig, free: jax.Array,
+             cpus: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """``(first node, found)`` of a placement on free capacity: best fit
+    for one node (fewest free GPUs that hold ``cpus``, lowest node on
+    ties), the lowest start of ``cpus / G`` contiguous free nodes
+    otherwise — `placement.fit_free`.  O(N) per queue position."""
+    g, n = cfg.node_size, cfg.n_nodes
+    ar = jnp.arange(n, dtype=jnp.int32)
+    fits = free >= cpus
+    best = jnp.argmin(jnp.where(fits, free, BIG)).astype(jnp.int32)
+    k = cpus // g
+    whole = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                             jnp.cumsum((free == g).astype(jnp.int32))])
+    window = (ar + k <= n) & (whole[jnp.minimum(ar + k, n)] - whole[ar] == k)
+    start = jnp.argmax(window).astype(jnp.int32)
+    one = cpus <= g
+    return jnp.where(one, best, start), jnp.where(one, jnp.any(fits),
+                                                  jnp.any(window))
+
+
+def take_nodes(cfg: SchedulerConfig, free: jax.Array, at: jax.Array,
+               cpus: jax.Array, admit: jax.Array) -> jax.Array:
+    """``free`` after a job of ``cpus`` is placed from node ``at``."""
+    g = cfg.node_size
+    ar = jnp.arange(cfg.n_nodes, dtype=jnp.int32)
+    span = jnp.maximum(cpus // g, 1)
+    held = jnp.where(cpus <= g, cpus, g)
+    return free - jnp.where(admit & (ar >= at) & (ar < at + span), held, 0)
+
+
+@jax.named_scope("sched.node_victims")
+def node_victim_order(cfg: SchedulerConfig, tbl: JobTable,
+                      order: jax.Array):
+    """``(rank[J], grouped[J], key[J])``: each row's rank in victim
+    ``order``; the order regrouped by node, victim order kept inside a
+    node (running single-node rows by node, every other row last); and
+    each grouped row's node key (N for the rest).  Hoisted once a tick
+    with the victim order where `_hoistable` holds: mid-pass, rows only
+    leave the evictable set, and a row that stays keeps its node."""
+    j = tbl.cpus.shape[0]
+    rank = jnp.zeros((j,), jnp.int32).at[order].set(
+        jnp.arange(j, dtype=jnp.int32))
+    single = (tbl.state == RUNNING) & (tbl.cpus <= cfg.node_size)
+    key = jnp.where(single, tbl.node, cfg.n_nodes)
+    grouped = order[jnp.argsort(key[order], stable=True)]
+    return rank, grouped, key[grouped]
+
+
+@jax.named_scope("sched.node_victims")
+def plan_node_evictions(cfg: SchedulerConfig, tbl: JobTable,
+                        evictable: jax.Array, free: jax.Array,
+                        cpus: jax.Array, ranks) -> Tuple[jax.Array, ...]:
+    """The node-aware victim prefix, `placement.plan_on_nodes` over the
+    table: ``(planned[J], enough, first node)``.  ``ranks`` is
+    `node_victim_order`'s triple.
+
+    One node (``cpus <= G``): a node's reach is the least rank at which
+    its free GPUs plus its evictable jobs' up to that rank hold ``cpus``
+    (a segmented cumsum over the node-grouped victim order), -1 where the
+    free GPUs do already; a node under a multi-node job reaches at that
+    job's rank.  Whole nodes: a node's clearing rank is the largest rank
+    on it (-1 free, BIG if a job on it is not evictable), and a window's
+    is the largest over its ``cpus / G`` nodes.  The least wins, lowest
+    node on ties; its jobs up to that rank are the victims."""
+    g, n = cfg.node_size, cfg.n_nodes
+    rank, grouped, key = ranks
+    running = tbl.state == RUNNING
+    single = running & (tbl.cpus <= g)
+    multi = running & (tbl.cpus > g)
+    rows = jnp.arange(tbl.cpus.shape[0], dtype=jnp.int32)
+    cover = _cover(cfg, tbl, multi, rows + 1) - 1
+    at_cover = jnp.maximum(cover, 0)
+    cover_rank = jnp.where((cover >= 0) & evictable[at_cover],
+                           rank[at_cover], BIG)
+    # one node: the reach of every node
+    ev = evictable[grouped] & (key < n)
+    gpus = jnp.where(ev, tbl.cpus[grouped], 0)
+    cum = jnp.cumsum(gpus)
+    base = jax.ops.segment_min(cum - gpus, key, num_segments=n + 1)
+    hit = ev & (free[jnp.minimum(key, n - 1)] + cum - base[key] >= cpus)
+    reach = jax.ops.segment_min(jnp.where(hit, rank[grouped], BIG), key,
+                                num_segments=n + 1)[:n]
+    reach = jnp.where(free >= cpus, -1,
+                      jnp.where(cover >= 0, cover_rank,
+                                jnp.minimum(reach, BIG)))
+    node = jnp.argmin(reach).astype(jnp.int32)
+    # whole nodes: the clearing rank of every window
+    k = cpus // g
+    ar = jnp.arange(n, dtype=jnp.int32)
+    clear = jax.ops.segment_max(jnp.where(evictable, rank, BIG),
+                                jnp.where(single, tbl.node, n),
+                                num_segments=n + 1)[:n]
+    clear = jnp.where(free == g, -1, jnp.where(cover >= 0, cover_rank, clear))
+    inside = (ar[None, :] >= ar[:, None]) & (ar[None, :] < ar[:, None] + k)
+    worst = jnp.max(jnp.where(inside, clear[None, :], -1), axis=1)
+    worst = jnp.where(ar + k <= n, worst, BIG)
+    start = jnp.argmin(worst).astype(jnp.int32)
+
+    one = cpus <= g
+    at = jnp.where(one, node, start)
+    cut = jnp.where(one, reach[node], worst[start])
+    span = jnp.where(tbl.cpus > g, tbl.cpus // g, 1)
+    on = running & (tbl.node < at + jnp.where(one, 1, k)) & (
+        tbl.node + span > at)
+    return evictable & on & (rank <= cut), cut < BIG, at
+
+
+# ---------------------------------------------------------------------------
 # Reference pass: one Algorithm-1 admission, everything recomputed (O(J))
 # ---------------------------------------------------------------------------
 
@@ -608,6 +773,10 @@ def make_omfs_pass(pass_depth: Optional[int] = None, incremental: bool = True,
     (`Knobs`): traced per-cell quantum / pass-depth overrides used by
     `engine.simulate_batch`.  ``knobs=None`` (every sequential caller)
     traces exactly the pre-batching program.
+
+    With ``cfg.node_size`` set the incremental pass also carries the free
+    GPUs per node (DESIGN.md §Node placement); the other passes and the
+    fused kernel raise.
     """
 
     def pass_fn(cfg: SchedulerConfig, ent: jax.Array, t: jax.Array,
@@ -616,6 +785,13 @@ def make_omfs_pass(pass_depth: Optional[int] = None, incremental: bool = True,
         order, eligible = queue_order(tbl)
         depth = n if pass_depth is None else min(pass_depth, n)
         quantum = cfg.quantum if knobs is None else knobs.quantum
+        nodes = cfg.node_size is not None
+        if nodes and (not incremental or cfg.kernel_backend != "lax"):
+            raise NotImplementedError(
+                "SchedulerConfig.node_size is placed by the incremental "
+                "OMFS pass on the lax backend only, not with "
+                f"incremental={incremental}, kernel_backend="
+                f"{cfg.kernel_backend!r}")
 
         # satellite hoist: one victim_order per tick (see _hoistable) —
         # the lax path reuses it across every admission of the pass; the
@@ -623,6 +799,8 @@ def make_omfs_pass(pass_depth: Optional[int] = None, incremental: bool = True,
         # the hoisted lexsort would only be dead weight there.
         hoist = cfg.kernel_backend == "lax" and _hoistable(cfg, knobs)
         vorder0 = victim_order(tbl, cheap_victims) if hoist else None
+        ranks0 = (node_victim_order(cfg, tbl, vorder0)
+                  if hoist and nodes else None)
 
         if not incremental:
             def body_ref(i, tbl):
@@ -636,9 +814,14 @@ def make_omfs_pass(pass_depth: Optional[int] = None, incremental: bool = True,
                 return jax.lax.fori_loop(0, depth, body_ref, tbl)
 
         usage0, nonp0, busy0 = running_usage(tbl, ent.shape[0])
+        if nodes:
+            with jax.named_scope("sched.place_nodes"):
+                free0 = node_free(cfg, tbl)
+        else:
+            free0 = None
 
         def body(i, carry):
-            tbl, usage, nonp_usage, busy = carry
+            tbl, usage, nonp_usage, busy, free = carry
             idx = order[i]
             ju = tbl.user[idx]
             jc = tbl.cpus[idx]
@@ -652,11 +835,17 @@ def make_omfs_pass(pass_depth: Optional[int] = None, incremental: bool = True,
             admit_26 = idle > jc
             reject_28 = jc > ent[ju] - usage[ju]
             ok = pending_now & ~reject_23
-            fast_admit = ok & admit_26
-            need_evict = ok & ~admit_26 & ~reject_28
+            if nodes:
+                # line 26 on nodes: the idle GPUs must hold a placement
+                at, fits = fit_free(cfg, free, jc)
+                fast_admit = ok & admit_26 & fits
+                need_evict = ok & ~fast_admit & ~reject_28
+            else:
+                fast_admit = ok & admit_26
+                need_evict = ok & ~admit_26 & ~reject_28
 
             def evict_case(carry):
-                tbl, usage, nonp_usage, busy = carry
+                tbl, usage, nonp_usage, busy, free = carry
                 running = tbl.state == RUNNING
                 preempt_able = tbl.jclass != NONP
                 evictable = running & preempt_able & (
@@ -665,8 +854,18 @@ def make_omfs_pass(pass_depth: Optional[int] = None, incremental: bool = True,
                     evictable = evictable & (tbl.user != ju)
                 if cfg.victim_filter_over_entitlement:  # beyond-paper flag
                     evictable = evictable & (usage[tbl.user] > ent[tbl.user])
-                planned, enough, vorder, placement = plan_evictions(
-                    cfg, tbl, evictable, idle, jc, cheap_victims, vorder0)
+                if nodes:
+                    vorder = (victim_order(tbl, cheap_victims)
+                              if vorder0 is None else vorder0)
+                    ranks = (node_victim_order(cfg, tbl, vorder)
+                             if ranks0 is None else ranks0)
+                    planned, enough, at = plan_node_evictions(
+                        cfg, tbl, evictable, free, jc, ranks)
+                    placement = None
+                else:
+                    planned, enough, vorder, placement = plan_evictions(
+                        cfg, tbl, evictable, idle, jc, cheap_victims,
+                        vorder0)
                 admit = enough
                 planned = planned & admit
                 freed = jnp.where(planned, tbl.cpus, 0)
@@ -675,31 +874,49 @@ def make_omfs_pass(pass_depth: Optional[int] = None, incremental: bool = True,
                     freed, tbl.user, num_segments=ent.shape[0])
                 busy = busy - jnp.sum(freed)
                 tbl = admit_job(tbl, idx, t, admit)
+                if nodes:
+                    tbl = _placed(tbl, idx, at, admit, admit & admit_26)
+                    free = node_free(cfg, tbl)
                 grant = jnp.where(admit, jc, 0)
                 usage = usage.at[ju].add(grant)
                 nonp_usage = nonp_usage.at[ju].add(
                     jnp.where(job_non_p, grant, 0))
                 busy = busy + grant
-                return tbl, usage, nonp_usage, busy
+                return tbl, usage, nonp_usage, busy, free
 
-            tbl, usage, nonp_usage, busy = jax.lax.cond(
+            tbl, usage, nonp_usage, busy, free = jax.lax.cond(
                 need_evict, evict_case, lambda c: c,
-                (tbl, usage, nonp_usage, busy))
+                (tbl, usage, nonp_usage, busy, free))
 
             # idle-admit fast path: no victim machinery, O(1) updates
             tbl = admit_job(tbl, idx, t, fast_admit)
+            if nodes:
+                tbl = _placed(tbl, idx, at, fast_admit)
+                free = take_nodes(cfg, free, at, jc, fast_admit)
             grant = jnp.where(fast_admit, jc, 0)
             usage = usage.at[ju].add(grant)
             nonp_usage = nonp_usage.at[ju].add(jnp.where(job_non_p, grant, 0))
             busy = busy + grant
-            return tbl, usage, nonp_usage, busy
+            return tbl, usage, nonp_usage, busy, free
 
         with jax.named_scope("sched.admit"):
-            tbl, _, _, _ = jax.lax.fori_loop(
-                0, depth, body, (tbl, usage0, nonp0, busy0))
+            tbl, _, _, _, _ = jax.lax.fori_loop(
+                0, depth, body, (tbl, usage0, nonp0, busy0, free0))
         return tbl
 
     return pass_fn
+
+
+def _placed(tbl: JobTable, idx: jax.Array, node: jax.Array,
+            admit: jax.Array, frag: Optional[jax.Array] = None) -> JobTable:
+    """Record job ``idx``'s placement from ``node`` iff ``admit``; count
+    an admission by eviction with more GPUs idle than it needs (``frag``)."""
+    tbl = tbl._replace(
+        node=tbl.node.at[idx].set(jnp.where(admit, node, tbl.node[idx])))
+    if frag is None:
+        return tbl
+    return tbl._replace(n_frag=tbl.n_frag.at[idx].add(
+        frag.astype(jnp.int32)))
 
 
 # ---------------------------------------------------------------------------
@@ -770,7 +987,7 @@ def update_state_mib(tbl: JobTable, idx, state_mib,
 #: not move any aggregate, and ``jid=BIG`` keeps it last in every
 #: tie-break.
 _PAD_VALUES = {"jid": int(BIG), "submit": int(BIG), "run_start": -1,
-               "first_start": -1, "finish": -1, "ckpt_tier": -1}
+               "first_start": -1, "finish": -1, "ckpt_tier": -1, "node": -1}
 
 
 def pad_table(tbl: JobTable, rows: int) -> JobTable:
@@ -791,7 +1008,7 @@ def _pad(tbl: JobTable, rows: int, xp) -> JobTable:
             [getattr(tbl, f),
              xp.full((k,) + getattr(tbl, f).shape[1:],
                      _PAD_VALUES.get(f, 0), xp.int32)])
-        for f in JobTable._fields})
+        for f in JobTable._fields if getattr(tbl, f) is not None})
 
 
 def is_pad(tbl: JobTable) -> jax.Array:
@@ -844,8 +1061,9 @@ def insert_rows(tbl: JobTable, slots: jax.Array, rows: JobTable,
         v = valid.reshape(valid.shape + (1,) * (col.ndim - 1))
         return col.at[slots].set(jnp.where(v, new, col[slots]))
 
-    return JobTable(*[put(getattr(tbl, f), getattr(rows, f))
-                      for f in JobTable._fields])
+    return JobTable(**{f: put(getattr(tbl, f), getattr(rows, f))
+                       for f in JobTable._fields
+                       if getattr(tbl, f) is not None})
 
 
 def pack_insert(rows: JobTable, slots: np.ndarray,
@@ -855,7 +1073,7 @@ def pack_insert(rows: JobTable, slots: np.ndarray,
     columns), then ``slots``, then ``valid``.  The device pays per array
     transferred, so the stream boundary sends this one (`insert_packed`)."""
     return np.concatenate(
-        [c.reshape(len(slots), -1) for c in rows]
+        [c.reshape(len(slots), -1) for c in rows if c is not None]
         + [slots[:, None], valid[:, None]], axis=1, dtype=np.int32)
 
 
@@ -865,6 +1083,8 @@ def insert_packed(tbl: JobTable, packed: jax.Array) -> JobTable:
     cols, at = {}, 0
     for f in JobTable._fields:
         col = getattr(tbl, f)
+        if col is None:
+            continue
         width = col.shape[1] if col.ndim == 2 else 1
         cols[f] = packed[:, at:at + width].reshape(col.shape)
         at += width

@@ -1,8 +1,9 @@
 """Core scheduler types: users, jobs, job classes, events.
 
 Terminology follows the paper: the resource unit is a "CPU" (for the TPU
-adaptation read "chip"; `core.placement` adds slice-shape constraints on
-top of the counts — Algorithm 1 itself only sees counts).
+adaptation read "chip", for a GPU fleet "GPU").  Algorithm 1 sees counts;
+with `SchedulerConfig.node_size` set, a job also needs a place on the
+fleet's nodes (`core.placement`, DESIGN.md §Node placement).
 """
 from __future__ import annotations
 
@@ -75,6 +76,8 @@ class Job:
     backfilled: bool = False       # admitted by jumping the queue (backfill)
     ckpt_tier: int = -1            # tier holding the latest snapshot (-1: none)
     n_spills: int = 0              # checkpoints placed beyond the fast tier
+    node: int = -1                 # first node of the latest placement (-1: never)
+    n_frag: int = 0                # admissions that evicted though idle > cpus
 
     @property
     def remaining(self) -> int:
@@ -116,6 +119,24 @@ class SchedulerConfig:
     # The flag rides every lru-cached runner key (the config is the key), so
     # toggling it selects a separately cached runner — never a retrace.
     kernel_backend: str = "lax"
+
+    # GPUs (CPUs) per node: None counts the machine as one pool (the
+    # paper); G places every job on one node (cpus <= G) or on cpus / G
+    # contiguous whole nodes (DESIGN.md §Node placement).  Static, so it
+    # rides every runner key like ``cr_tiers``.
+    node_size: Optional[int] = None
+
+    def __post_init__(self):
+        g = self.node_size
+        if g is not None and (g <= 0 or self.cpu_total % g):
+            raise ValueError(
+                f"node_size {g} must be positive and divide cpu_total "
+                f"{self.cpu_total}")
+
+    @property
+    def n_nodes(self) -> int:
+        """Nodes of the machine, N = cpu_total // node_size."""
+        return self.cpu_total // self.node_size
 
     # -- the one cost expression both backends share (DESIGN.md §Tier
     # placement): the JAX backend precomputes these per JobTable column with

@@ -46,9 +46,9 @@ from typing import Dict, Iterator
 import jax
 
 #: the device scopes of the jitted code, in the order a tick runs them
-SCOPES = ("sched.queue_order", "sched.admit", "sched.plan_evictions",
-          "sched.victim_order", "sched.place_checkpoints", "sched.capture",
-          "stream.insert_rows")
+SCOPES = ("sched.queue_order", "sched.admit", "sched.place_nodes",
+          "sched.plan_evictions", "sched.victim_order", "sched.node_victims",
+          "sched.place_checkpoints", "sched.capture", "stream.insert_rows")
 
 
 @contextmanager

@@ -128,7 +128,8 @@ def test_stream_build_counts_rows_and_bytes(traced):
     cfg = SchedulerConfig(cpu_total=16, quantum=2, cr_overhead=1)
     block, _ = omfs_jax.table_from_jobs([], users, cfg.cpu_total, cfg,
                                         rows=CAPACITY, host=True)
-    want = 4 * CAPACITY * (sum(c[0].size for c in block) + 2)
+    want = 4 * CAPACITY * (sum(c[0].size for c in block if c is not None)
+                           + 2)
     assert {s.args["h2d_bytes"] for s in builds} == {want}
 
 
@@ -181,7 +182,8 @@ def test_insert_rows_carries_its_scope():
         tbl, jnp.arange(8, dtype=jnp.int32), tbl, jnp.ones(8, bool))
     assert "stream.insert_rows" in _compiled_scopes(lowered)
     assert set(SCOPES) == {"sched.queue_order", "sched.admit",
-                           "sched.plan_evictions", "sched.victim_order",
+                           "sched.place_nodes", "sched.plan_evictions",
+                           "sched.victim_order", "sched.node_victims",
                            "sched.place_checkpoints", "sched.capture",
                            "stream.insert_rows"}
 
